@@ -84,15 +84,20 @@ def shard_rows(fast: bool = False) -> List[str]:
     return rows
 
 
-def million_peer_rows(fast: bool = False) -> List[str]:
-    k = 1_000_000
-    B = 512 if fast else 10_000
+def million_peer_cells(B: int, k: int = 1_000_000) -> List[CellSpec]:
+    """B class-pooled gossip cells of k-peer jobs (per-job MTBF 250 s)."""
     scen = scenario("constant", mtbf=250.0 * 1e6)
     pol = PolicyConfig(kind="adaptive", prior_mu=1.0 / (250.0 * 1e6),
                        prior_v=V, regime="gossip", gossip_period=600.0,
                        gossip_fanout=2)
-    cells = [CellSpec(scenario=scen, policy=pol, seed=s, k=k, n_slots=4 * k,
-                      work=1800.0, V=V, T_d=TD) for s in range(B)]
+    return [CellSpec(scenario=scen, policy=pol, seed=s, k=k, n_slots=4 * k,
+                     work=1800.0, V=V, T_d=TD) for s in range(B)]
+
+
+def million_peer_rows(fast: bool = False) -> List[str]:
+    k = 1_000_000
+    B = 512 if fast else 10_000
+    cells = million_peer_cells(B, k)
     t0 = time.monotonic()
     res = run_cells(cells, backend="jax", mesh="auto")
     us = (time.monotonic() - t0) * 1e6

@@ -34,6 +34,10 @@ def main() -> None:
             ap.error(f"--only: unknown section(s) {', '.join(unknown)}; "
                      f"valid choices: {', '.join(SECTIONS)}")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     def want(name: str) -> bool:
         return only is None or name in only
 

@@ -30,6 +30,7 @@ import ast
 import dataclasses
 import fnmatch
 import re
+import tomllib
 from pathlib import Path
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -147,50 +148,7 @@ def _read_pyproject_table(path: Path) -> dict:
     if not path.is_file():
         return {}
     text = path.read_text(encoding="utf-8")
-    try:
-        import tomllib  # py >= 3.11
-    except ModuleNotFoundError:
-        try:
-            import tomli as tomllib  # pytest dependency on py < 3.11
-        except ModuleNotFoundError:
-            return _fallback_toml_table(text)
-    try:
-        return tomllib.loads(text).get("tool", {}).get("reprolint", {})
-    except Exception:
-        return _fallback_toml_table(text)
-
-
-def _fallback_toml_table(text: str) -> dict:
-    """Minimal ``[tool.reprolint]`` reader (string / string-list values
-    only) for environments with no TOML parser at all."""
-    out: dict = {}
-    in_table = False
-    pending_key = None
-    pending: List[str] = []
-    for raw in text.splitlines():
-        line = raw.strip()
-        if line.startswith("["):
-            in_table = line == "[tool.reprolint]"
-            continue
-        if not in_table or not line or line.startswith("#"):
-            continue
-        if pending_key is not None:
-            pending.append(line)
-            if "]" in line:
-                out[pending_key] = re.findall(r'"([^"]*)"', " ".join(pending))
-                pending_key, pending = None, []
-            continue
-        m = re.match(r'^([A-Za-z0-9_-]+)\s*=\s*(.*)$', line)
-        if not m:
-            continue
-        key, val = m.group(1), m.group(2).strip()
-        if val.startswith("[") and "]" not in val:
-            pending_key, pending = key, [val]
-        elif val.startswith("["):
-            out[key] = re.findall(r'"([^"]*)"', val)
-        elif val.startswith('"'):
-            out[key] = val.strip('"')
-    return out
+    return tomllib.loads(text).get("tool", {}).get("reprolint", {})
 
 
 def path_matches(relpath: str, entries: Sequence[str]) -> bool:

@@ -27,7 +27,7 @@ import shutil
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, List, Optional, Sequence
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -51,6 +51,8 @@ class AsyncCheckpointer:
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
     last_blocking_seconds: float = field(default=0.0, repr=False)
     last_write_seconds: float = field(default=0.0, repr=False)
+    last_restored: Optional[Tuple[int, str]] = field(default=None, repr=False)
+    last_restore_seconds: float = field(default=0.0, repr=False)
 
     def __post_init__(self):
         os.makedirs(self.root, exist_ok=True)
@@ -141,8 +143,12 @@ class AsyncCheckpointer:
         Candidates from the primary and every replica are tried newest
         first (ties prefer the primary): with R-way placement the newest
         image may live only on the HRW-chosen neighbours, and a corrupt or
-        missing copy falls back to the next-newest surviving replica.
+        missing copy falls back to the next-newest surviving replica.  Any
+        other error, such as a ``like`` of other shapes, propagates.  The
+        (step, directory) read is kept in ``last_restored``, and the
+        seconds the whole search and load took in ``last_restore_seconds``.
         """
+        t0 = time.monotonic()
         found = []
         for root in (self.root, *self.replicas):
             got = store.latest_checkpoint(root)
@@ -150,9 +156,12 @@ class AsyncCheckpointer:
                 found.append(got)
         for step, path in sorted(found, key=lambda sp: sp[0], reverse=True):
             try:
-                return step, store.load_pytree(path, like)
-            except Exception:
-                continue  # corrupt copy — try the next candidate
+                tree = store.load_pytree(path, like)
+            except (OSError, KeyError):
+                continue  # corrupt or missing copy: try the next candidate
+            self.last_restored = (step, path)
+            self.last_restore_seconds = time.monotonic() - t0
+            return step, tree
         return None
 
     def gc(self, keep: int = 3) -> None:

@@ -25,6 +25,7 @@ import hashlib
 import json
 import os
 import shutil
+import zipfile
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -35,6 +36,12 @@ Params = Any
 
 _MANIFEST = "manifest.json"
 _COMMITTED = "COMMITTED"
+
+
+class CorruptCheckpoint(IOError):
+    """A committed copy whose files are damaged on disk (unreadable shard
+    or manifest, manifest/shard disagreement, integrity-hash mismatch).
+    Restore skips such a copy and tries the next one."""
 
 
 def _leaf_paths(tree) -> List[Tuple[str, np.ndarray]]:
@@ -134,18 +141,30 @@ def is_committed(path: str) -> bool:
 
 
 def load_pytree(path: str, like: Params, *, verify: bool = True) -> Params:
-    """Load a checkpoint into the structure of ``like`` (shapes validated)."""
+    """Load a checkpoint into the structure of ``like`` (shapes validated).
+
+    A missing or damaged copy raises ``FileNotFoundError``,
+    :class:`CorruptCheckpoint` or, for a leaf the manifest lacks,
+    ``KeyError``; a ``like`` whose shapes differ from the checkpoint's
+    raises ``ValueError``.
+    """
     if not is_committed(path):
         raise FileNotFoundError(f"checkpoint at {path} is not committed")
-    with open(os.path.join(path, _MANIFEST)) as f:
-        manifest = json.load(f)
+    try:
+        with open(os.path.join(path, _MANIFEST)) as f:
+            manifest = json.load(f)
+    except ValueError as e:
+        raise CorruptCheckpoint(f"unreadable manifest in {path}") from e
 
     cache: Dict[int, Any] = {}
 
-    def shard(s: int):
-        if s not in cache:
-            cache[s] = np.load(os.path.join(path, f"shard_{s}.npz"))
-        return cache[s]
+    def read(s: int, key: str) -> np.ndarray:
+        try:
+            if s not in cache:
+                cache[s] = np.load(os.path.join(path, f"shard_{s}.npz"))
+            return cache[s][key]
+        except (ValueError, EOFError, zipfile.BadZipFile) as e:
+            raise CorruptCheckpoint(f"unreadable shard {s} in {path}") from e
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(like)
     out = []
@@ -154,18 +173,19 @@ def load_pytree(path: str, like: Params, *, verify: bool = True) -> Params:
         if name not in manifest["leaves"]:
             raise KeyError(f"leaf {name!r} missing from checkpoint {path}")
         meta = manifest["leaves"][name]
-        arr = shard(meta["shard"])[meta["key"]]
+        arr = read(meta["shard"], meta["key"])
         if str(arr.dtype) != meta["dtype"]:
             # integer view of an ml_dtype (bfloat16/fp8): reinterpret
             import ml_dtypes  # noqa: F401  (registers the dtypes)
             arr = arr.view(np.dtype(meta["dtype"]))
         if list(arr.shape) != meta["shape"] or str(arr.dtype) != meta["dtype"]:
-            raise ValueError(f"leaf {name!r}: manifest/shard mismatch")
+            raise CorruptCheckpoint(f"leaf {name!r}: manifest/shard mismatch")
         if tuple(arr.shape) != tuple(np.shape(leaf)):
             raise ValueError(
                 f"leaf {name!r}: checkpoint shape {arr.shape} != expected {np.shape(leaf)}")
         if verify and _hash(arr) != meta["sha256_16"]:
-            raise IOError(f"leaf {name!r}: integrity hash mismatch (corrupt shard)")
+            raise CorruptCheckpoint(
+                f"leaf {name!r}: integrity hash mismatch (corrupt shard)")
         out.append(arr)
     return jax.tree_util.tree_unflatten(treedef, out)
 

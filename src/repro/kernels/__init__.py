@@ -1,3 +1,10 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels: flash attention, Mamba2 SSD scan, int8 block quantizer
+and the engine's fused sim step."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: on the CPU backend
+    only.  Every other backend compiles them, and a kernel that its
+    compiler refuses fails loudly rather than falling back."""
+    return jax.default_backend() == "cpu"

@@ -12,7 +12,10 @@ pair implements int8 gradient compression with error feedback
 
 Tiling: the flat input is viewed as (n_blocks, block); each grid step
 stages one (block_rows x block) tile into VMEM, computes row-wise absmax
-scales on the VPU, and writes int8 codes + fp32 scales.
+scales on the VPU, and writes int8 codes + fp32 scales.  The scales
+travel as an (n_blocks, 1) column, blocked (block_rows, 1): a rank-1
+(block_rows,) block of a 1-D f32 array is refused by Mosaic, whose
+(256)-tiling of it does not match XLA's (1024)-tiling of the operand.
 """
 from __future__ import annotations
 
@@ -22,7 +25,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
@@ -31,12 +33,12 @@ def _quant_kernel(x_ref, q_ref, s_ref):
     scale = jnp.where(amax > 0.0, amax / 127.0, 1.0)
     q = jnp.clip(jnp.round(x / scale), -127.0, 127.0)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale[:, 0]
+    s_ref[...] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
     q = q_ref[...].astype(jnp.float32)
-    x_ref[...] = (q * s_ref[...][:, None]).astype(x_ref.dtype)
+    x_ref[...] = (q * s_ref[...]).astype(x_ref.dtype)
 
 
 def quantize_blocks(x: jnp.ndarray, block: int = 512, *,
@@ -55,15 +57,15 @@ def quantize_blocks(x: jnp.ndarray, block: int = 512, *,
         in_specs=[pl.BlockSpec((block_rows, block), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((block_rows, block), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_blocks, block), jnp.int8),
-            jax.ShapeDtypeStruct((n_blocks,), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xb)
-    return q.reshape(-1), s
+    return q.reshape(-1), s.reshape(-1)
 
 
 def dequantize_blocks(q: jnp.ndarray, scales: jnp.ndarray, block: int = 512, *,
@@ -80,10 +82,10 @@ def dequantize_blocks(q: jnp.ndarray, scales: jnp.ndarray, block: int = 512, *,
         grid=(n_blocks // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, block), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks, block), dtype),
         interpret=interpret,
-    )(qb, scales)
+    )(qb, scales.reshape(n_blocks, 1))
     return x.reshape(-1)

@@ -1,8 +1,8 @@
 """Jitted public wrappers over the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the kernel
-body runs as a traced python function — bit-identical control flow to the
-TPU lowering); on a real TPU ``interpret`` flips to False automatically.
+``interpret=None`` defers to :func:`repro.kernels.interpret_mode`: on the
+CPU backend the kernel body runs as a traced python function (the same
+control flow as the TPU lowering); on a TPU it is compiled by Mosaic.
 """
 from __future__ import annotations
 
@@ -14,11 +14,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ckpt_quant as _q
 from repro.kernels import flash_attention as _fa
+from repro.kernels import interpret_mode
 from repro.kernels import ssd_scan as _ssd
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "softcap",
@@ -27,7 +24,7 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
                     softcap: Optional[float] = None, block_q: int = 128,
                     block_kv: int = 128, interpret: Optional[bool] = None):
     """GQA flash attention: q (BG, R, Sq, D), k/v (BG, Skv, D)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     return _fa.flash_attention(q, k, v, scale=scale, causal=causal,
                                softcap=softcap, block_q=block_q,
                                block_kv=block_kv, interpret=interpret)
@@ -37,7 +34,7 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
 def ssd_scan(x, dt, A, B, C, *, chunk: int = 256, initial_state=None,
              interpret: Optional[bool] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Mamba2 SSD: x (b,s,h,p), dt (b,s,h), A (h,), B/C (b,s,n)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     return _ssd.ssd_scan(x, dt, A, B, C, chunk=chunk,
                          initial_state=initial_state, interpret=interpret)
 
@@ -45,13 +42,13 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 256, initial_state=None,
 @functools.partial(jax.jit, static_argnames=("block", "block_rows", "interpret"))
 def quantize_blocks(x, *, block: int = 512, block_rows: int = 256,
                     interpret: Optional[bool] = None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     return _q.quantize_blocks(x, block, block_rows=block_rows, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "block_rows", "dtype", "interpret"))
 def dequantize_blocks(q, scales, *, block: int = 512, block_rows: int = 256,
                       dtype=jnp.float32, interpret: Optional[bool] = None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     return _q.dequantize_blocks(q, scales, block, block_rows=block_rows,
                                 dtype=dtype, interpret=interpret)

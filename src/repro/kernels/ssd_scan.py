@@ -24,14 +24,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX renamed TPUCompilerParams to CompilerParams across releases; accept
-# whichever this install provides (same fields either way).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
-
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
+def _ssd_kernel(a_ref, x_ref, dtc_ref, dtr_ref, b_ref, c_ref, init_ref,
                 y_ref, final_ref, state_scr, *, chunk: int):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -39,37 +35,41 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
     def _init():
         state_scr[...] = init_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # (Q,)
-    A = a_ref[0]                                     # scalar decay rate (<0)
+    x = x_ref[0, 0].astype(jnp.float32)              # (Q, P)
+    A = a_ref[hi]                                    # scalar decay rate (<0)
+    dt_col = dtc_ref[0, 0]                           # (Q, 1)
+    dA_col = dt_col * A                              # (Q, 1)
+    dA_row = dtr_ref[0, 0] * A                       # (1, Q)
     B = b_ref[0].astype(jnp.float32)                 # (Q, N)
     C = c_ref[0].astype(jnp.float32)                 # (Q, N)
 
-    dA = dt * A                                      # (Q,)
-    cum = jnp.cumsum(dA)                             # within-chunk cumulative
-
-    # ---- intra-chunk (dual/quadratic) term --------------------------------
-    li = cum[:, None]
-    lj = cum[None, :]
+    # Within-chunk cumulative decay, as a column and as a row: masked
+    # reductions over the (Q, Q) causal triangle, no relayout of a vector.
     iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(iota_i >= iota_j, jnp.exp(li - lj), 0.0)
+    causal = iota_i >= iota_j
+    cum_col = jnp.sum(jnp.where(causal, dA_row, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(iota_i <= iota_j, dA_col, 0.0), axis=0,
+                      keepdims=True)
+    total = jnp.sum(dA_row, axis=1, keepdims=True)   # (1, 1)
+
+    # ---- intra-chunk (dual/quadratic) term --------------------------------
+    L = jnp.where(causal, jnp.exp(cum_col - cum_row), 0.0)
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())))  # (Q, Q)
-    dtx = x * dt[:, None]                                         # (Q, P)
+    dtx = x * dt_col                                              # (Q, P)
     y_intra = jax.lax.dot_general(scores * L, dtx, (((1,), (0,)), ((), ())))
 
     # ---- inter-chunk term ---------------------------------------------------
     state_in = state_scr[...]                                     # (P, N)
-    decay_from_start = jnp.exp(cum)[:, None]                      # (Q, 1)
-    y_inter = jax.lax.dot_general(C * decay_from_start, state_in,
+    y_inter = jax.lax.dot_general(C * jnp.exp(cum_col), state_in,
                                   (((1,), (1,)), ((), ())))       # (Q, P)
-    y_ref[0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # ---- state update ---------------------------------------------------------
-    decay_to_end = jnp.exp(cum[-1] - cum)[:, None]                # (Q, 1)
-    contrib = jax.lax.dot_general(dtx * decay_to_end, B,
-                                  (((0,), (0,)), ((), ())))       # (P, N)
-    state_scr[...] = state_in * jnp.exp(cum[-1]) + contrib
+    decayed = dtx * jnp.exp(total - cum_col)                      # (Q, P)
+    contrib = jax.lax.dot_general(decayed.T, B,
+                                  (((1,), (0,)), ((), ())))       # (P, N)
+    state_scr[...] = state_in * jnp.exp(total) + contrib
 
     @pl.when(ci == nc - 1)
     def _final():
@@ -83,6 +83,13 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     """x: (b, s, h, p); dt: (b, s, h); A: (h,); B, C: (b, s, n).
 
     Returns (y (b, s, h, p), final_state (b, h, p, n)).
+
+    The kernel works head-major: x and y move as (b, h, s, p) and dt as a
+    (b, h, s, 1) column plus a (b, h, 1, s) row, so that every block's
+    last two dimensions are (chunk, full) tiles.  A (1, chunk, 1, p) block
+    of the (b, s, h, p) array is refused by the TPU lowering, whose tiles
+    need the second-to-last block dimension to be a multiple of 8.  A sits
+    whole in SMEM and is read per head.
     """
     b, s, h, p = x.shape
     n = B.shape[-1]
@@ -91,30 +98,34 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     nc = s // chunk
     if initial_state is None:
         initial_state = jnp.zeros((b, h, p, n), jnp.float32)
+    xh = jnp.transpose(x, (0, 2, 1, 3))                      # (b, h, s, p)
+    dth = jnp.transpose(dt, (0, 2, 1)).astype(jnp.float32)   # (b, h, s)
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     y, final = pl.pallas_call(
         kernel,
         grid=(b, h, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, 0, ci)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, A.astype(jnp.float32), B, C, initial_state)
-    return y, final
+    )(A.astype(jnp.float32), xh, dth[..., None], dth[:, :, None, :], B, C,
+      initial_state)
+    return jnp.transpose(y, (0, 2, 1, 3)), final

@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.policy import PolicyRequest
 from repro.serve.policy_service import PolicyService
 
@@ -166,6 +167,7 @@ def main() -> int:
     ap.add_argument("--p99-budget", type=float, default=2.0,
                     help="smoke gate: max allowed p99 flush latency (s)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         return run_smoke(args)
     if args.port:

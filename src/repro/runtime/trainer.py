@@ -102,9 +102,14 @@ class FaultTolerantTrainer:
         self.virtual_restore_time = virtual_restore_time
 
         self.data = SyntheticLM(data_cfg)
+        # The state is donated: the step's new state reuses its buffers, so
+        # one copy of params + optimizer state lives on the device, not two
+        # (olmo-1b at half depth fits one 16 GB chip only this way).  Saves
+        # snapshot to host before the next step consumes the buffers.
         self.train_step = jax.jit(
             make_train_step(cfg, opt_cfg, constant(1.0),
-                            n_microbatches=n_microbatches))
+                            n_microbatches=n_microbatches),
+            donate_argnums=0)
         self._seed = seed
 
     # ------------------------------------------------------------------ #
@@ -134,14 +139,14 @@ class FaultTolerantTrainer:
         n_fail = n_ckpt = n_restart = wasted = 0
         last_ckpt_vtime = 0.0
         committed_step = 0
+        vclock = lambda: (self.injector.virtual_time if self.injector else
+                          float(step) * 1.0)
         if resume:
             restored = self.ckpt.restore_latest(state)
             if restored is not None:
                 committed_step, state = restored
                 step = committed_step
-
-        vclock = lambda: (self.injector.virtual_time if self.injector else
-                          float(step) * 1.0)
+                last_ckpt_vtime = vclock()  # the image just loaded
 
         while step < n_steps:
             batch = self.data.batch_at(step)

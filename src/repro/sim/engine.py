@@ -1527,7 +1527,6 @@ if _HAVE_JAX:
         fn = _SHARDED_CACHE.get(key)
         if fn is not None:
             return fn
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def body(s, keys, pj):
@@ -1542,8 +1541,8 @@ if _HAVE_JAX:
         in_specs = (jax.tree.map(lead, s_tmpl), lead(k_tmpl),
                     jax.tree.map(lead, p_tmpl))
         out_specs = (jax.tree.map(lead, s_tmpl), lead(k_tmpl), P())
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                   out_specs=out_specs, check_vma=False))
         _SHARDED_CACHE[key] = fn
         return fn
 
@@ -1553,7 +1552,7 @@ def _run_jax(p: _Params, seeds: Sequence[int], max_steps: int,
              any_shock: bool, any_pm: bool, peer_axis: int, chunk: int,
              mesh, step: str) -> tuple:
     global _jax_chunk_jit
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         B = len(seeds)
         seeds = list(seeds)
         axes = None
@@ -1645,7 +1644,9 @@ def run_cells(cells: Sequence[CellSpec], *, backend: str = "auto",
     ``step``: inner-step implementation on the JAX backend — "auto"
     (``REPRO_SIM_STEP`` env var, else "scan"), "scan" (stock ``lax.scan``
     body), "fused" (the Pallas kernel of :mod:`repro.kernels.sim_step`;
-    requires a batch with no per-peer-form cells, and runs unsharded).
+    requires a batch with no per-peer-form cells, runs unsharded, and
+    runs only in Pallas interpret mode on the CPU backend: elsewhere it
+    raises ``NotImplementedError`` naming the compiler's refusal).
     """
     if backend == "auto":
         backend = os.environ.get("REPRO_SIM_BACKEND") or (

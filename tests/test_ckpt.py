@@ -122,6 +122,25 @@ def test_restore_falls_back_when_primary_corrupt(tmp_path, tree):
     ck.close()
 
 
+def test_restore_into_wrongly_shaped_like_raises(tmp_path, tree):
+    """Only damaged or missing copies are skipped: a ``like`` of other
+    shapes is the caller's error and must surface, not read as "no
+    checkpoint" (the trainer would then carry on from memory)."""
+    ck = AsyncCheckpointer(str(tmp_path / "p"), replicas=[str(tmp_path / "r")],
+                           n_shards=2)
+    ck.save(3, tree)
+    ck.wait()
+    bad = dict(tree)
+    bad["params"] = {"w": jnp.zeros((8, 8)), "b": tree["params"]["b"]}
+    with pytest.raises(ValueError, match="checkpoint shape"):
+        ck.restore_latest(bad)
+    assert ck.last_restored is None
+    step, _ = ck.restore_latest(tree)
+    assert ck.last_restored == (3, os.path.join(str(tmp_path / "p"),
+                                                "step_00000003"))
+    ck.close()
+
+
 def test_truncated_primary_falls_through_to_replica(tmp_path, tree):
     """Torn-write hardening: a TRUNCATED shard (partial write, not garbage)
     must fail the load — bad zip or integrity hash — and restore must fall

@@ -15,8 +15,7 @@ import pytest
 
 from repro.analysis import (LintConfig, RULES, lint_paths, lint_source,
                             render_json)
-from repro.analysis.core import (_fallback_toml_table, parse_suppressions,
-                                 path_matches)
+from repro.analysis.core import parse_suppressions, path_matches
 
 ROOT = Path(__file__).resolve().parent.parent
 FIXTURES = Path(__file__).parent / "lint_fixtures"
@@ -125,19 +124,6 @@ def test_pyproject_config_is_loaded():
     assert "tests/lint_fixtures" in cfg.exclude
     assert "B001" in cfg.report_only
     assert any(p.endswith("trainer.py") for p in cfg.r003_allow)
-
-
-def test_fallback_toml_parser_matches_real_parser():
-    text = (ROOT / "pyproject.toml").read_text(encoding="utf-8")
-    fall = _fallback_toml_table(text)
-    try:
-        import tomllib
-    except ModuleNotFoundError:
-        tomllib = pytest.importorskip("tomli")
-    real = tomllib.loads(text)["tool"]["reprolint"]
-    for key, val in real.items():
-        if isinstance(val, list):
-            assert list(fall[key]) == val, key
 
 
 def test_path_matching_covers_dirs_and_globs():
