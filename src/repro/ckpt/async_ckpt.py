@@ -32,6 +32,7 @@ from typing import Any, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.ckpt import store
 from repro.p2p.overlay import rendezvous_placement
 
@@ -67,31 +68,37 @@ class AsyncCheckpointer:
             item = self._q.get()
             if item is None:
                 return
-            step, snapshot = item
+            step, snapshot, cause = item
             try:
-                t0 = time.monotonic()
-                path = store.save_pytree(self.root, step, snapshot, self.n_shards)
-                for r in self._placement(step):
-                    dst = os.path.join(r, os.path.basename(path))
-                    # Atomic replication: copy into a ``.tmp`` sibling —
-                    # invisible to list_checkpoints — and rename into place,
-                    # so a crash mid-copy never leaves a half-written
-                    # replica that restore_latest could mistake for a
-                    # committed image (its COMMITTED marker would already
-                    # have been copied by a plain copytree).
-                    tmp = dst + ".tmp"
-                    if os.path.exists(tmp):
-                        shutil.rmtree(tmp)
-                    shutil.copytree(path, tmp)
-                    if os.path.exists(dst):
-                        shutil.rmtree(dst)
-                    os.rename(tmp, dst)
-                self.last_write_seconds = time.monotonic() - t0
+                with tracing.span("ckpt.write", id=step, parent=cause) as sp:
+                    path = store.save_pytree(self.root, step, snapshot,
+                                             self.n_shards)
+                    for r in self._placement(step):
+                        with tracing.span("ckpt.replicate"):
+                            self._replicate(path, r)
+                self.last_write_seconds = sp.seconds
             except BaseException as e:
                 self._exc = e
             finally:
                 with self._lock:
                     self._pending -= 1
+
+    @staticmethod
+    def _replicate(path: str, replica_root: str) -> None:
+        """Copy one committed image into a replica directory, atomically:
+        copy into a ``.tmp`` sibling (invisible to list_checkpoints) and
+        rename into place, so a crash mid-copy never leaves a half-written
+        replica that restore_latest could mistake for a committed image
+        (its COMMITTED marker would already have been copied by a plain
+        copytree)."""
+        dst = os.path.join(replica_root, os.path.basename(path))
+        tmp = dst + ".tmp"
+        if os.path.exists(tmp):
+            shutil.rmtree(tmp)
+        shutil.copytree(path, tmp)
+        if os.path.exists(dst):
+            shutil.rmtree(dst)
+        os.rename(tmp, dst)
 
     def _placement(self, step: int) -> Sequence[str]:
         """Replica directories receiving this step's image."""
@@ -107,26 +114,31 @@ class AsyncCheckpointer:
         if self._exc is not None:
             exc, self._exc = self._exc, None
             raise exc
-        t0 = time.monotonic()
-        # Snapshot to host memory so the device arrays can keep training.
-        snapshot = jax.tree.map(lambda x: np.asarray(x), tree)
-        with self._lock:
-            self._pending += 1
-        self._q.put((step, snapshot))  # blocks only when 2 saves are queued
-        blocking = time.monotonic() - t0
-        self.last_blocking_seconds = blocking
-        return blocking
+        with tracing.span("ckpt.save", id=step) as sp:
+            # Snapshot to host memory so the device arrays can keep training.
+            with tracing.span("ckpt.snapshot"):
+                snapshot = jax.tree.map(lambda x: np.asarray(x), tree)
+            with self._lock:
+                self._pending += 1
+            with tracing.span("ckpt.enqueue"):
+                # blocks only when 2 saves are queued
+                self._q.put((step, snapshot, sp))
+        tracing.count("ckpt.saves")
+        self.last_blocking_seconds = sp.seconds
+        return sp.seconds
 
     def wait(self, timeout: Optional[float] = None) -> None:
         """Block until all queued saves have landed."""
         deadline = None if timeout is None else time.monotonic() + timeout
-        while True:
-            with self._lock:
-                if self._pending == 0:
-                    break
-            if deadline is not None and time.monotonic() > deadline:
-                raise TimeoutError("async checkpoint writes did not finish")
-            time.sleep(0.005)
+        with tracing.span("ckpt.wait"):
+            while True:
+                with self._lock:
+                    if self._pending == 0:
+                        break
+                if deadline is not None and time.monotonic() > deadline:
+                    raise TimeoutError(
+                        "async checkpoint writes did not finish")
+                time.sleep(0.005)
         if self._exc is not None:
             exc, self._exc = self._exc, None
             raise exc
@@ -148,7 +160,16 @@ class AsyncCheckpointer:
         (step, directory) read is kept in ``last_restored``, and the
         seconds the whole search and load took in ``last_restore_seconds``.
         """
-        t0 = time.monotonic()
+        with tracing.span("ckpt.restore") as sp:
+            got = self._load_newest(like)
+        if got is None:
+            return None
+        step, path, tree = got
+        self.last_restored = (step, path)
+        self.last_restore_seconds = sp.seconds
+        return step, tree
+
+    def _load_newest(self, like: Params) -> Optional[tuple]:
         found = []
         for root in (self.root, *self.replicas):
             got = store.latest_checkpoint(root)
@@ -156,12 +177,9 @@ class AsyncCheckpointer:
                 found.append(got)
         for step, path in sorted(found, key=lambda sp: sp[0], reverse=True):
             try:
-                tree = store.load_pytree(path, like)
+                return step, path, store.load_pytree(path, like)
             except (OSError, KeyError):
                 continue  # corrupt or missing copy: try the next candidate
-            self.last_restored = (step, path)
-            self.last_restore_seconds = time.monotonic() - t0
-            return step, tree
         return None
 
     def gc(self, keep: int = 3) -> None:

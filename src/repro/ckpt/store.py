@@ -32,6 +32,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import tracing
+
 Params = Any
 
 _MANIFEST = "manifest.json"
@@ -66,9 +68,12 @@ def _atomic_write(path: str, writer) -> None:
     """
     part = path + ".part"
     with open(part, "wb") as f:
-        writer(f)
-        f.flush()
-        os.fsync(f.fileno())
+        with tracing.span("ckpt.serialize"):
+            writer(f)
+            f.flush()
+        with tracing.span("ckpt.fsync"):
+            os.fsync(f.fileno())
+        tracing.count("ckpt.bytes_written", f.tell())
     os.replace(part, path)
 
 
@@ -79,7 +84,8 @@ def _fsync_dir(path: str) -> None:
     except OSError:  # pragma: no cover - platform without dir-open support
         return
     try:
-        os.fsync(fd)
+        with tracing.span("ckpt.fsync"):
+            os.fsync(fd)
     except OSError:  # pragma: no cover - filesystems that reject dir fsync
         pass
     finally:
@@ -106,33 +112,35 @@ def save_pytree(root: str, step: int, tree: Params, n_shards: int = 4) -> str:
 
     manifest: Dict[str, Any] = {"step": step, "n_shards": n_shards, "leaves": {}}
     shards: Dict[int, Dict[str, np.ndarray]] = {}
-    for name, arr in leaves:
-        s = shard_of[name]
-        key = f"a{len(shards.setdefault(s, {}))}"
-        # npz cannot store ml_dtypes (bfloat16/fp8): persist a same-width
-        # integer view; the true dtype is recorded in the manifest.
-        stored = arr
-        if arr.dtype.name not in np.sctypeDict:
-            stored = arr.view(np.dtype(f"u{arr.dtype.itemsize}"))
-        shards[s][key] = stored
-        manifest["leaves"][name] = {
-            "shard": s, "key": key, "shape": list(arr.shape),
-            "dtype": str(arr.dtype), "sha256_16": _hash(arr),
-        }
+    with tracing.span("ckpt.hash"):
+        for name, arr in leaves:
+            s = shard_of[name]
+            key = f"a{len(shards.setdefault(s, {}))}"
+            # npz cannot store ml_dtypes (bfloat16/fp8): persist a same-width
+            # integer view; the true dtype is recorded in the manifest.
+            stored = arr
+            if arr.dtype.name not in np.sctypeDict:
+                stored = arr.view(np.dtype(f"u{arr.dtype.itemsize}"))
+            shards[s][key] = stored
+            manifest["leaves"][name] = {
+                "shard": s, "key": key, "shape": list(arr.shape),
+                "dtype": str(arr.dtype), "sha256_16": _hash(arr),
+            }
 
     for s, arrs in shards.items():
         _atomic_write(os.path.join(tmp, f"shard_{s}.npz"),
                       lambda f, arrs=arrs: np.savez(f, **arrs))
     _atomic_write(os.path.join(tmp, _MANIFEST),
                   lambda f: f.write(json.dumps(manifest).encode()))
-    # The marker is written (and fsynced) last: its presence certifies that
-    # every shard above it is complete on disk.
-    _atomic_write(os.path.join(tmp, _COMMITTED), lambda f: f.write(b"ok"))
-    _fsync_dir(tmp)
-    if os.path.exists(final):
-        shutil.rmtree(final)
-    os.rename(tmp, final)
-    _fsync_dir(root)
+    with tracing.span("ckpt.commit"):
+        # The marker is written (and fsynced) last: its presence certifies
+        # that every shard above it is complete on disk.
+        _atomic_write(os.path.join(tmp, _COMMITTED), lambda f: f.write(b"ok"))
+        _fsync_dir(tmp)
+        if os.path.exists(final):
+            shutil.rmtree(final)
+        os.rename(tmp, final)
+        _fsync_dir(root)
     return final
 
 

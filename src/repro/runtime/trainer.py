@@ -21,13 +21,13 @@ intervals on a *real* training job, reproducing Eq. 11 end-to-end.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.core.adaptive import AdaptiveCheckpointController
 from repro.ckpt.async_ckpt import AsyncCheckpointer
@@ -149,23 +149,24 @@ class FaultTolerantTrainer:
                 last_ckpt_vtime = vclock()  # the image just loaded
 
         while step < n_steps:
-            batch = self.data.batch_at(step)
-            t0 = time.monotonic()
+            i = step  # the id of this step's spans
+            with tracing.span("train.batch", id=i):
+                batch = self.data.batch_at(step)
             try:
-                if self.injector is not None:
-                    self.injector.advance_step()
-                new_state, metrics = self.train_step(state, batch)
-                jax.block_until_ready(metrics["loss"])
+                with tracing.span("train.step", id=i) as sp:
+                    if self.injector is not None:
+                        self.injector.advance_step()
+                    new_state, metrics = self.train_step(state, batch)
+                    jax.block_until_ready(metrics["loss"])
             except SimulatedFailure as f:
                 # ---- failure: rollback to last committed checkpoint ----
                 n_fail += 1
                 self.controller.observe_failure(f.lifetime)
                 self._feed_observations()
-                restore_t0 = time.monotonic()
-                restored = self.ckpt.restore_latest(state)
-                real_restore = time.monotonic() - restore_t0
+                with tracing.span("train.restore", id=i) as restore_sp:
+                    restored = self.ckpt.restore_latest(state)
                 t_d = (self.virtual_restore_time if self.virtual_restore_time
-                       is not None else real_restore)
+                       is not None else restore_sp.seconds)
                 if self.injector is not None:
                     self.injector.advance_seconds(t_d)
                 self.controller.observe_restore(t_d)
@@ -182,20 +183,24 @@ class FaultTolerantTrainer:
                     self.shrink_fleet(self.k - 1)
                 continue
 
-            real_dt = time.monotonic() - t0
+            real_dt = sp.seconds
             state = new_state
+            with tracing.span("train.loss", id=i):
+                losses.append(float(metrics["loss"]))
             step += 1
-            losses.append(float(metrics["loss"]))
-            self.controller.observe_step(real_dt)
-            self._feed_observations()
-            if self.straggler.observe(host=0, step_seconds=real_dt):
-                # a flagged straggler counts as a departure event
-                self.controller.observe_failure(self.straggler.ema * 10)
 
             # ---- checkpoint decision (the paper's core loop) -------------
-            since_last = vclock() - last_ckpt_vtime
-            if self.controller.should_checkpoint(since_last) if self.policy.kind == "adaptive" \
-                    else since_last >= self.policy.fixed_interval:
+            with tracing.span("train.decide", id=i):
+                self.controller.observe_step(real_dt)
+                self._feed_observations()
+                if self.straggler.observe(host=0, step_seconds=real_dt):
+                    # a flagged straggler counts as a departure event
+                    self.controller.observe_failure(self.straggler.ema * 10)
+                since_last = vclock() - last_ckpt_vtime
+                due = (self.controller.should_checkpoint(since_last)
+                       if self.policy.kind == "adaptive"
+                       else since_last >= self.policy.fixed_interval)
+            if due:
                 blocking = self.ckpt.save(step, state)
                 v = (self.virtual_ckpt_overhead if self.virtual_ckpt_overhead
                      is not None else blocking)

@@ -211,6 +211,7 @@ from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.lambertw import lambertw0_numpy
 from repro.p2p.store import R_MAX as _R_MAX
 from repro.p2p.store import StoreSpec
@@ -1510,6 +1511,14 @@ if _HAVE_JAX:
         (s, keys), _ = jax.lax.scan(body, state_and_keys, None, length=chunk)
         return s, keys
 
+    def engine_chunk(state_and_keys, p: _Params, macro_threshold: float,
+                     any_store: bool, any_het: bool, any_shock: bool,
+                     any_pm: bool, peer_axis: int, chunk: int):
+        """``_jax_chunk``, jitted under a stable program name: its runs
+        show as ``jit_engine_chunk`` in a profile."""
+        return _jax_chunk(state_and_keys, p, macro_threshold, any_store,
+                          any_het, any_shock, any_pm, peer_axis, chunk)
+
     _jax_chunk_jit = None  # compiled lazily (needs x64 enabled at trace time)
     _SHARDED_CACHE: dict = {}  # (mesh, statics...) -> jitted shard_map chunk
 
@@ -1529,7 +1538,7 @@ if _HAVE_JAX:
             return fn
         from jax.sharding import PartitionSpec as P
 
-        def body(s, keys, pj):
+        def engine_chunk(s, keys, pj):  # the program: jit_engine_chunk
             s, keys = _jax_chunk((s, keys), pj, macro_threshold, any_store,
                                  any_het, any_shock, any_pm, peer_axis, chunk)
             unfin = jax.lax.psum(
@@ -1541,7 +1550,7 @@ if _HAVE_JAX:
         in_specs = (jax.tree.map(lead, s_tmpl), lead(k_tmpl),
                     jax.tree.map(lead, p_tmpl))
         out_specs = (jax.tree.map(lead, s_tmpl), lead(k_tmpl), P())
-        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+        fn = jax.jit(jax.shard_map(engine_chunk, mesh=mesh, in_specs=in_specs,
                                    out_specs=out_specs, check_vma=False))
         _SHARDED_CACHE[key] = fn
         return fn
@@ -1573,45 +1582,55 @@ def _run_jax(p: _Params, seeds: Sequence[int], max_steps: int,
                     [a, np.repeat(a[-1:], pad, axis=0)]) for a in p))
                 seeds = seeds + [seeds[-1]] * pad
         if _jax_chunk_jit is None:
-            _jax_chunk_jit = jax.jit(_jax_chunk,
+            _jax_chunk_jit = jax.jit(engine_chunk,
                                      static_argnums=(2, 3, 4, 5, 6, 7, 8))
-        pj = _Params(*(jnp.asarray(a) for a in p))
-        keys = jax.vmap(jax.random.PRNGKey)(
-            jnp.asarray(list(seeds), dtype=jnp.uint32))
-        s = _init_state(pj, jnp, peer_axis)
-        if len(seeds) != B:
-            s = s._replace(finished=s.finished
-                           | (jnp.arange(len(seeds)) >= B))
-        steps = 0
+        # Host time to dispatch the transfers and the initial state: the
+        # copies are asynchronous and finish inside the first chunk or sync.
+        with tracing.span("sim.upload"):
+            pj = _Params(*(jnp.asarray(a) for a in p))
+            keys = jax.vmap(jax.random.PRNGKey)(
+                jnp.asarray(list(seeds), dtype=jnp.uint32))
+            s = _init_state(pj, jnp, peer_axis)
+            if len(seeds) != B:
+                s = s._replace(finished=s.finished
+                               | (jnp.arange(len(seeds)) >= B))
         if step == "fused":
             from repro.kernels.sim_step import fused_chunk
 
-            while steps < max_steps:
+            def advance(s, keys):
                 s, keys = fused_chunk(
                     s, keys, pj, macro_threshold=macro_threshold,
                     any_store=any_store, any_het=any_het,
                     any_shock=any_shock, any_pm=any_pm, chunk=chunk)
-                steps += chunk
-                if bool(s.finished.all()):
-                    break
+                return s, keys, lambda: not bool(s.finished.all())
         elif axes is not None:
             fn = _get_sharded_chunk(mesh, axes, macro_threshold, any_store,
                                     any_het, any_shock, any_pm, peer_axis,
                                     chunk, (s, keys, pj))
-            while steps < max_steps:
+
+            def advance(s, keys):
                 s, keys, unfin = fn(s, keys, pj)
-                steps += chunk
-                if int(unfin) == 0:
-                    break
+                return s, keys, lambda: int(unfin) != 0
         else:
-            while steps < max_steps:
+            def advance(s, keys):
                 s, keys = _jax_chunk_jit((s, keys), pj, macro_threshold,
                                          any_store, any_het, any_shock,
                                          any_pm, peer_axis, chunk)
-                steps += chunk
-                if bool(s.finished.all()):
-                    break
-        return _State(*(np.asarray(a)[:B] for a in s)), steps
+                return s, keys, lambda: not bool(s.finished.all())
+        steps = 0
+        while steps < max_steps:
+            with tracing.span("sim.chunk"):
+                s, keys, unfinished = advance(s, keys)
+            steps += chunk
+            # The early-exit check waits for the chunk to finish on the
+            # device and reads one number back.
+            with tracing.span("sim.sync"):
+                running = unfinished()
+            tracing.count("sim.host_syncs")
+            if not running:
+                break
+        with tracing.span("sim.download"):
+            return _State(*(np.asarray(a)[:B] for a in s)), steps
 
 
 # --------------------------------------------------------------------------- #
@@ -1665,54 +1684,58 @@ def run_cells(cells: Sequence[CellSpec], *, backend: str = "auto",
     if chunk < 1:
         raise ValueError("chunk must be >= 1")
 
-    p = _pack(cells, peer_form)
-    seeds = [c.seed for c in cells]
-    any_store = any(c.store is not None for c in cells)
-    any_het = bool(p.store_mix.any())
-    any_shock = any(_cell_shock(c) is not None for c in cells)
-    any_pm = bool(p.pm_on.any())
-    # Per-peer estimator state is only materialized when some cell needs it
-    # (class-pooled cells keep their decision row in slot 0 of a width-1
-    # axis, so an all-pm batch stays narrow at any k).
-    peer_axis = (_PEER_CAP if any(
-        c.policy.regime != "pooled" and not pm
-        for c, pm in zip(cells, p.pm_on)) else 1)
-    if step == "fused":
-        if backend != "jax":
-            raise ValueError("step='fused' requires the JAX backend")
-        if peer_axis != 1:
-            raise ValueError(
-                "step='fused' supports batches with no per-peer-form cells "
-                "(pooled or class-pooled estimators only)")
-    if backend == "jax":
-        mesh_obj = None
-        if mesh == "auto":
-            if len(jax.devices()) > 1:
-                from repro.distributed.mesh import cell_mesh
-                mesh_obj = cell_mesh()
-        elif mesh is not None:
-            mesh_obj = mesh
-        s, steps = _run_jax(p, seeds, max_steps, float(macro_threshold),
-                            any_store, any_het, any_shock, any_pm, peer_axis,
-                            chunk, mesh_obj, step)
-    else:
-        s, steps = _run_numpy(p, seeds, max_steps, float(macro_threshold),
-                              any_store, any_het, any_shock, any_pm,
-                              peer_axis)
+    # Calls are counted, so that other counters can be read per call; the
+    # running count is also this call's span id.
+    with tracing.span("sim.run_cells", id=tracing.count("sim.run_cells")):
+        with tracing.span("sim.pack"):
+            p = _pack(cells, peer_form)
+        seeds = [c.seed for c in cells]
+        any_store = any(c.store is not None for c in cells)
+        any_het = bool(p.store_mix.any())
+        any_shock = any(_cell_shock(c) is not None for c in cells)
+        any_pm = bool(p.pm_on.any())
+        # Per-peer estimator state is only materialized when some cell needs
+        # it (class-pooled cells keep their decision row in slot 0 of a
+        # width-1 axis, so an all-pm batch stays narrow at any k).
+        peer_axis = (_PEER_CAP if any(
+            c.policy.regime != "pooled" and not pm
+            for c, pm in zip(cells, p.pm_on)) else 1)
+        if step == "fused":
+            if backend != "jax":
+                raise ValueError("step='fused' requires the JAX backend")
+            if peer_axis != 1:
+                raise ValueError(
+                    "step='fused' supports batches with no per-peer-form "
+                    "cells (pooled or class-pooled estimators only)")
+        if backend == "jax":
+            mesh_obj = None
+            if mesh == "auto":
+                if len(jax.devices()) > 1:
+                    from repro.distributed.mesh import cell_mesh
+                    mesh_obj = cell_mesh()
+            elif mesh is not None:
+                mesh_obj = mesh
+            s, steps = _run_jax(p, seeds, max_steps, float(macro_threshold),
+                                any_store, any_het, any_shock, any_pm,
+                                peer_axis, chunk, mesh_obj, step)
+        else:
+            s, steps = _run_numpy(p, seeds, max_steps, float(macro_threshold),
+                                  any_store, any_het, any_shock, any_pm,
+                                  peer_axis)
 
-    ran_out = ~np.asarray(s.finished)
-    completed = ~(np.asarray(s.censored) | ran_out)
-    return BatchResult(
-        wall_time=np.asarray(s.t) - p.t0,
-        work_required=p.work / p.speed,
-        n_checkpoints=np.asarray(s.n_ckpt).astype(np.int64),
-        n_failures=np.asarray(s.n_fail).astype(np.int64),
-        wasted_work=np.asarray(s.wasted),
-        checkpoint_time=np.asarray(s.ckpt_time),
-        restore_time=np.asarray(s.restore_time),
-        completed=completed,
-        server_bytes=np.asarray(s.sv_bytes),
-        n_server_restores=np.asarray(s.n_srv).astype(np.int64),
-        n_peer_restores=np.asarray(s.n_peer).astype(np.int64),
-        n_steps=steps,
-    )
+        ran_out = ~np.asarray(s.finished)
+        completed = ~(np.asarray(s.censored) | ran_out)
+        return BatchResult(
+            wall_time=np.asarray(s.t) - p.t0,
+            work_required=p.work / p.speed,
+            n_checkpoints=np.asarray(s.n_ckpt).astype(np.int64),
+            n_failures=np.asarray(s.n_fail).astype(np.int64),
+            wasted_work=np.asarray(s.wasted),
+            checkpoint_time=np.asarray(s.ckpt_time),
+            restore_time=np.asarray(s.restore_time),
+            completed=completed,
+            server_bytes=np.asarray(s.sv_bytes),
+            n_server_restores=np.asarray(s.n_srv).astype(np.int64),
+            n_peer_restores=np.asarray(s.n_peer).astype(np.int64),
+            n_steps=steps,
+        )
