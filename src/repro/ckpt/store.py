@@ -26,6 +26,7 @@ import json
 import os
 import shutil
 import zipfile
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -56,7 +57,33 @@ def _leaf_paths(tree) -> List[Tuple[str, np.ndarray]]:
 
 
 def _hash(arr: np.ndarray) -> str:
-    return hashlib.sha256(arr.tobytes()).hexdigest()[:16]
+    # The bytes of ``tobytes()`` (C order), read in place: no copy for a
+    # C-contiguous array, and a byte view also covers ml_dtypes and 0-d.
+    flat = np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
+    return hashlib.sha256(flat).hexdigest()[:16]
+
+
+def _cpus() -> int:
+    """The cores this process may run on (its affinity, where the OS has one)."""
+    if hasattr(os, "sched_getaffinity"):
+        return len(os.sched_getaffinity(0))
+    return os.cpu_count() or 1
+
+
+def _hash_leaves(arrs: List[np.ndarray]) -> List[str]:
+    """``_hash`` of each array, in their order.
+
+    Hashed on a thread pool of one thread per leaf, up to the cores this
+    process may use (``hashlib`` releases the GIL for large buffers),
+    largest leaves first since the largest sets the floor.  Counts the
+    threads in ``ckpt.hash_threads``.
+    """
+    workers = max(1, min(len(arrs), _cpus()))
+    tracing.count("ckpt.hash_threads", workers)
+    by_size = sorted(range(len(arrs)), key=lambda i: -arrs[i].nbytes)
+    with ThreadPoolExecutor(workers) as pool:
+        done = {i: pool.submit(_hash, arrs[i]) for i in by_size}
+        return [done[i].result() for i in range(len(arrs))]
 
 
 def _atomic_write(path: str, writer) -> None:
@@ -113,7 +140,8 @@ def save_pytree(root: str, step: int, tree: Params, n_shards: int = 4) -> str:
     manifest: Dict[str, Any] = {"step": step, "n_shards": n_shards, "leaves": {}}
     shards: Dict[int, Dict[str, np.ndarray]] = {}
     with tracing.span("ckpt.hash"):
-        for name, arr in leaves:
+        digests = _hash_leaves([arr for _, arr in leaves])
+        for (name, arr), digest in zip(leaves, digests):
             s = shard_of[name]
             key = f"a{len(shards.setdefault(s, {}))}"
             # npz cannot store ml_dtypes (bfloat16/fp8): persist a same-width
@@ -124,7 +152,7 @@ def save_pytree(root: str, step: int, tree: Params, n_shards: int = 4) -> str:
             shards[s][key] = stored
             manifest["leaves"][name] = {
                 "shard": s, "key": key, "shape": list(arr.shape),
-                "dtype": str(arr.dtype), "sha256_16": _hash(arr),
+                "dtype": str(arr.dtype), "sha256_16": digest,
             }
 
     for s, arrs in shards.items():

@@ -1,10 +1,13 @@
 """Checkpoint store + async checkpointer: atomicity, integrity, replication."""
+import hashlib
+import json
 import os
 import shutil
 import time
 
 import jax
 import jax.numpy as jnp
+import ml_dtypes
 import numpy as np
 import pytest
 
@@ -14,7 +17,9 @@ from repro.ckpt import (
     list_checkpoints,
     load_pytree,
     save_pytree,
+    store,
 )
+from repro.ckpt.store import CorruptCheckpoint
 
 
 @pytest.fixture()
@@ -236,3 +241,89 @@ def test_blocking_time_much_smaller_than_write(tmp_path):
     assert ck.last_write_seconds > 0
     assert blocking <= max(ck.last_write_seconds, 0.05) * 5  # overlapped
     ck.close()
+
+
+# ------------------------------------------------------ the manifest's hash
+def _old_hash(a):
+    return hashlib.sha256(a.tobytes()).hexdigest()[:16]
+
+
+def _big_tree():
+    """Over 16 MiB, with more leaves than hashing threads."""
+    rng = np.random.default_rng(0)
+    n_small = store._cpus() + 3
+    return {
+        "big": [rng.standard_normal(3 << 19, dtype=np.float32)
+                for _ in range(3)],
+        "small": [rng.standard_normal((i + 1, 7), dtype=np.float32)
+                  for i in range(n_small)],
+        "bf16": rng.standard_normal((64, 33)).astype(ml_dtypes.bfloat16),
+        "step": np.int32(7),
+    }
+
+
+_base = np.arange(24, dtype=np.float32).reshape(4, 6)
+
+
+@pytest.mark.parametrize("a", [
+    _base,
+    _base.astype(ml_dtypes.bfloat16),
+    np.array(7, dtype=np.int32),
+    _base.T,
+    np.asfortranarray(_base),
+    np.zeros((0, 3), np.float32),
+], ids=["float32", "bfloat16", "0d-int32", "transposed", "fortran", "empty"])
+def test_hash_equals_the_digest_of_tobytes(a):
+    assert store._hash(a) == _old_hash(a)
+
+
+def test_parallel_hash_writes_the_serial_manifest(tmp_path):
+    tree = _big_tree()
+    assert sum(a.nbytes for a in jax.tree.leaves(tree)) > 16 << 20
+    path = save_pytree(str(tmp_path), 3, tree, n_shards=3)
+    with open(os.path.join(path, "manifest.json"), "rb") as f:
+        written = f.read()
+    saved = json.loads(written)["leaves"]
+    # The manifest the serial ``sha256(tobytes())`` loop wrote, leaves in
+    # flattening order.
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    names = ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in p)
+             for p, _ in flat]
+    serial = {"step": 3, "n_shards": 3, "leaves": {
+        name: {**saved[name], "sha256_16": _old_hash(np.asarray(leaf))}
+        for name, (_, leaf) in zip(names, flat)}}
+    assert written == json.dumps(serial).encode()
+
+    out = load_pytree(path, tree, verify=True)
+    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    shard = max((os.path.join(path, f) for f in os.listdir(path)
+                 if f.endswith(".npz")), key=os.path.getsize)
+    with open(shard, "r+b") as f:
+        f.seek(os.path.getsize(shard) // 2)
+        byte = f.read(1)
+        f.seek(-1, os.SEEK_CUR)
+        f.write(bytes([byte[0] ^ 0xFF]))
+    with pytest.raises(CorruptCheckpoint):
+        load_pytree(path, tree, verify=True)
+
+
+@pytest.mark.parametrize("big", [False, True], ids=["small", "large"])
+def test_a_hash_error_reaches_the_caller_and_commits_nothing(
+        tmp_path, tree, monkeypatch, big):
+    if big:
+        tree = _big_tree()
+    victim = min(jax.tree.leaves(tree), key=lambda a: np.asarray(a).size)
+    real = store._hash
+
+    def failing(a):
+        if a.shape == np.shape(victim) and a.dtype == np.asarray(victim).dtype:
+            raise RuntimeError("hash failed")
+        return real(a)
+
+    monkeypatch.setattr(store, "_hash", failing)
+    with pytest.raises(RuntimeError, match="hash failed"):
+        save_pytree(str(tmp_path), 1, tree)
+    assert list_checkpoints(str(tmp_path)) == []
+    assert not os.path.exists(os.path.join(str(tmp_path), "step_00000001"))

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro import tracing
-from repro.ckpt import AsyncCheckpointer, latest_checkpoint
+from repro.ckpt import AsyncCheckpointer, latest_checkpoint, store
 from repro.configs import get_smoke_config
 from repro.data import DataConfig
 from repro.runtime import CheckpointPolicyConfig, FaultTolerantTrainer
@@ -188,6 +188,26 @@ def test_save_and_write_phases_cover_the_write(tmp_path, tree):
     on_disk = sum(e.stat().st_size for e in os.scandir(path))
     assert _delta(before, "ckpt.saves") == 1
     assert _delta(before, "ckpt.bytes_written") == on_disk
+
+
+def test_a_save_counts_the_threads_that_hashed_it(tmp_path):
+    ck = AsyncCheckpointer(str(tmp_path / "p"), n_shards=2)
+    before = tracing.counters()
+    ck.save(1, {"w": np.zeros((4, 4), np.float32)})
+    ck.wait()
+    assert _delta(before, "ckpt.hash_threads") == 1      # one leaf
+    cpus = store._cpus()
+    n = cpus + 1
+    big = {f"w{i}": np.full(((16 << 20) // (4 * n)) + 1, i, np.float32)
+           for i in range(n)}
+    before = tracing.counters()
+    ck.save(2, big)
+    ck.wait()
+    ck.close()
+    threads = _delta(before, "ckpt.hash_threads")
+    assert threads == cpus
+    if cpus > 1:
+        assert threads > 1
 
 
 def test_replicas_and_restore_fallbacks_are_spanned(tmp_path, tree):
