@@ -11,9 +11,9 @@ cell's own sizes, on each seed.  With ``--program``: the program's own
 readings, from the cell's set-up and first timed step, as a run takes
 them (on the chip).  For an engine cell: the readings of a run cut to its
 set-up and one timed sweep, with each control and fault of
-``bench/faults.py`` in the program's place (``--program``: the program's
-own).  Each reading prints as one
-JSON line.
+``bench/faults.py`` in the program's place, on several chips also the
+faults only they can have (``--program``: the program's own).  Each
+reading prints as one JSON line.
 """
 from __future__ import annotations
 
@@ -29,17 +29,17 @@ sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 def trainer_readings(cell, seed: int, n_steps: int):
     from bench import registry, seeds
     from bench.compare import max_rel_gap, worst_leaf_gap
-    from bench.reference import olmo
 
     s32 = seeds.derive(seed, registry.system("trainer").SEED_TAG)
     data = cell.traffic["data"]
-    ref = olmo.train(cell.config, data, s32, n_steps)
+    train = registry.reference(cell.config["model_type"]).train
+    ref = train(cell.config, data, s32, n_steps)
     half = int(cell.config["run"]["global_batch"]) // 2
     variants = {
-        "control_fp8": olmo.train(cell.config, data, s32, n_steps,
-                                  precision="fp8"),
-        "fault_half_batch": olmo.train(cell.config, data, s32, n_steps,
-                                       rows=range(half)),
+        "control_fp8": train(cell.config, data, s32, n_steps,
+                             precision="fp8"),
+        "fault_half_batch": train(cell.config, data, s32, n_steps,
+                                  rows=range(half)),
     }
     for name, r in variants.items():
         yield {
@@ -71,7 +71,8 @@ def program_readings(cell, seed: int, reading: str = "program"):
 def engine_readings(cell, seed: int):
     from bench import faults
 
-    for name, make in faults.ENGINE.items():
+    found = dict(faults.ENGINE, **(faults.SHARDED if cell.chips > 1 else {}))
+    for name, make in found.items():
         with faults.planted(make(cell.config)):
             yield from program_readings(cell, seed, reading=name)
 

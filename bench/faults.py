@@ -2,10 +2,13 @@
 
 Each entry of ``ENGINE`` takes the cell's configuration and gives a
 stand-in for ``repro.sim.run_cells``, ``f(real_run_cells, cells, kw)``;
-``planted(f)`` puts it in place for a whole run.  ``bench/control.py``
-reads them on the chip at the cell's own size, where they set the upper
-readings of the limits, and ``bench/tests/test_correct.py`` sees each turn
-``correct`` false on the CPU.
+``planted(f)`` puts it in place for a whole run.  ``SHARDED`` holds the
+faults that only a cell of several chips can have; they break the calls
+that pass a mesh and leave the others alone.  ``bench/control.py`` reads
+them on the chip at the cell's own size, where they set the upper
+readings of the limits, and ``bench/tests/test_correct.py`` and
+``bench/tests/test_four_devices.py`` see each turn ``correct`` false on
+the CPU.
 """
 from __future__ import annotations
 
@@ -106,3 +109,47 @@ ENGINE = {"control_f32": control_f32, "fault_rate_x2": rate_x2,
           "fault_state_unchanged": state_unchanged,
           "fault_answer_altered": answer_altered,
           "fault_half_left_out": half_left_out}
+
+
+def shards_swapped(config):
+    """The results of the first two shards swapped as they come back from
+    the chips: each cell's fields stay together, in another cell's place."""
+    def f(real, cells, kw):
+        res = real(cells, **kw)
+        mesh = kw.get("mesh")
+        if mesh is None:
+            return res
+        per = -(-len(cells) // mesh.size)
+        order = np.arange(len(cells))
+        order[:2 * per] = np.roll(order[:2 * per], per)
+        return dataclasses.replace(res, **{
+            fld.name: getattr(res, fld.name)[order]
+            for fld in dataclasses.fields(res)
+            if isinstance(getattr(res, fld.name), np.ndarray)})
+    return f
+
+
+def exchange_left_out(config):
+    """The exchange between chips left out: the chunk's psum of the
+    unfinished count is the local count, so the host reads the first
+    shard's and ends the sweep once that shard's cells are done."""
+    broken_programs: Dict[Any, Any] = {}
+
+    def f(real, cells, kw):
+        if kw.get("mesh") is None:
+            return real(cells, **kw)
+        import jax
+        import repro.sim.engine as eng
+
+        saved = eng._SHARDED_CACHE, jax.lax.psum
+        eng._SHARDED_CACHE = broken_programs
+        jax.lax.psum = lambda x, *a, **k: x
+        try:
+            return real(cells, **kw)
+        finally:
+            eng._SHARDED_CACHE, jax.lax.psum = saved
+    return f
+
+
+SHARDED = {"fault_shards_swapped": shards_swapped,
+           "fault_exchange_left_out": exchange_left_out}

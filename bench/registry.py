@@ -8,6 +8,11 @@ metric is a file of its own, named after it:
     bench/traffic/<traffic>.json   parameters that the system's generator reads
     bench/metrics/<metric>.py      ``read(ctx)``: the number, or None
     bench/systems/<system>.py      ``run(cell, seed, window, rec, dev)``
+    bench/models/<model_type>.py   a trained model's ``model_config(cfg)``
+                                   and ``train_flops_per_token(cfg)``
+    bench/reference/<model_type>.py  its plain reference's ``train(...)``
+
+A trainer configuration names its ``model_type`` (HF's own key).
 
 So a new cell, mix or metric is new files plus new entries in
 ``BENCHMARK.json``; no file here changes.
@@ -93,11 +98,28 @@ def system(name: str, root: Path = ROOT) -> ModuleType:
                       f"bench_system_{name}")
 
 
+def _named_file(kind: str, name: str, what: str, root: Path) -> ModuleType:
+    path = root / "bench" / kind / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"{what} {name!r} has no file at "
+                       f"{path.relative_to(root)}")
+    return _load_file(path, f"bench_{kind}_" + name.replace(".", "_")
+                      .replace("-", "_"))
+
+
 def metric_reader(name: str, root: Path = ROOT) -> ModuleType:
     """``bench/metrics/<name>.py``, whose ``read(ctx)`` gives the metric."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise KeyError(f"per-layer metric {name!r} has no reader at "
-                       f"{path.relative_to(root)}")
-    return _load_file(path, "bench_metric_" + name.replace(".", "_")
-                      .replace("-", "_"))
+    return _named_file("metrics", name, "per-layer metric", root)
+
+
+def model(model_type: str, root: Path = ROOT) -> ModuleType:
+    """``bench/models/<model_type>.py``: ``model_config(cfg)``, the
+    program's ModelConfig, and ``train_flops_per_token(cfg)``."""
+    return _named_file("models", model_type, "model_type", root)
+
+
+def reference(model_type: str, root: Path = ROOT) -> ModuleType:
+    """``bench/reference/<model_type>.py``: the plain reference, whose
+    ``train(cfg, data, seed, n_steps, precision=, rows=)`` gives its
+    readings."""
+    return _named_file("reference", model_type, "model_type", root)

@@ -1,10 +1,14 @@
 """The Monte-Carlo engine under the benchmark: closed-loop sweeps through
-run_cells on one device.
+run_cells on the cell's chips.
 
-Set-up runs one sweep of the cell's size, which compiles (or loads) the
-only program the window uses.  The window then runs sweeps back to back,
-each of ``cells_per_sweep`` cells with fresh seeds derived from ``--seed``
-and the sweep's index, through ``repro.sim.run_cells``.
+A one-chip cell runs on one device (``mesh=None``), also on a host with
+more; a cell of several chips shards each sweep's cells over them through
+the program's own ``cell_mesh``, as ``run_cells(mesh="auto")`` does on a
+host with that many.  Set-up runs one sweep of the cell's size, which
+compiles (or loads) the only program the window uses.  The window then
+runs sweeps back to back, each of ``cells_per_sweep`` cells with fresh
+seeds derived from ``--seed`` and the sweep's index, through
+``repro.sim.run_cells``.
 
 Checks, over every cell of every sweep in the window:
 
@@ -17,11 +21,18 @@ Checks, over every cell of every sweep in the window:
   configured failure rate, nothing of the program), run after the window
   on ``reference_cells`` cells drawn from the seed: each sweep's mean wall
   time, checkpoints, failures and waste, as a relative gap to the
-  reference's mean; the largest over the sweeps is compared.
+  reference's mean; the largest over the sweeps is compared;
+* on several chips, the window's first sweep against the same cells run
+  again on one device after the window, one chip's share of them at a
+  time (the one-chip cell's program where the shares are equal): every
+  result field of every cell must be equal bit for bit.  All cells of a
+  sweep share one configuration, so a cell's results in another cell's
+  place pass every check above; this one sees them.
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import sys
 import time
 from typing import Any, Dict, List
@@ -95,6 +106,46 @@ def cell_checks(results: List[Any], config: Dict[str, Any]
     }
 
 
+def cells_differing(got, want) -> int:
+    """Cells whose results differ from ``want``'s in any field, bit for
+    bit; every cell when the fields' shapes or types differ."""
+    n = len(want.wall_time)
+    differ = np.zeros(n, bool)
+    for fld in dataclasses.fields(want):
+        b = getattr(want, fld.name)
+        if not isinstance(b, np.ndarray):
+            continue
+        a = np.asarray(getattr(got, fld.name))
+        if a.shape != b.shape or a.dtype != b.dtype:
+            return n
+        bits = np.dtype(f"u{b.dtype.itemsize}")
+        differ |= (np.ascontiguousarray(a).view(bits)
+                   != np.ascontiguousarray(b).view(bits))
+    return int(differ.sum())
+
+
+def shard(res, k: int, per: int):
+    """Cells ``[k * per, (k + 1) * per)`` of a batch's results."""
+    return dataclasses.replace(res, **{
+        fld.name: getattr(res, fld.name)[k * per:(k + 1) * per]
+        for fld in dataclasses.fields(res)
+        if isinstance(getattr(res, fld.name), np.ndarray)})
+
+
+def one_device_check(run_cells, cells, got, chips: int, step: str):
+    """``cells``, whose sharded results are ``got``, again on one device,
+    one chip's share at a time: the cells that differ bit for bit, and the
+    engine steps each share ran to."""
+    per = -(-len(cells) // chips)
+    differing, steps = 0, []
+    for k in range(chips):
+        one = run_cells(cells[k * per:(k + 1) * per], backend="jax",
+                        step=step, mesh=None)
+        differing += cells_differing(shard(got, k, per), one)
+        steps.append(int(one.n_steps))
+    return differing, steps
+
+
 def reference(config: Dict[str, Any], seed: int) -> Dict[str, np.ndarray]:
     """The plain checkpoint process on ``reference_cells`` cells."""
     return ckpt_process.simulate(
@@ -122,15 +173,27 @@ def run(cell, *, seed: int, window, rec, dev) -> Outcome:
     config, traffic = cell.config, cell.traffic
     n = int(traffic["cells_per_sweep"])
     base = seeds.derive(seed, SEED_TAG, bits=30)
+    if cell.chips > 1:
+        from repro.distributed.mesh import cell_mesh
+
+        mesh = cell_mesh(cell.chips)
+    else:
+        mesh = None
+
+    def sweep_cells(j: int):
+        with rec.span("engine.cells"):
+            return fleet_cells.engine_cells(
+                config, traffic, fleet_cells.sweep_seeds(base, j, n))
 
     def sweep(j: int):
-        with rec.span("engine.cells"):
-            cells = fleet_cells.engine_cells(
-                config, traffic, fleet_cells.sweep_seeds(base, j, n))
-        return run_cells(cells, backend="jax", step=config["step"],
-                         mesh=None)
+        return run_cells(sweep_cells(j), backend="jax", step=config["step"],
+                         mesh=mesh)
 
+    t_setup = time.monotonic()
     sweep(0)                      # set-up: the window's one program
+    print(f"bench: set-up {t_setup - window.t_process:.1f} s to the first "
+          f"sweep, which took {time.monotonic() - t_setup:.1f} s",
+          file=sys.stderr)
     results: List[Any] = []
     log: List[tuple] = []     # (start, end, cells completed) per sweep
     with _spans_inside_run_cells(rec):
@@ -157,6 +220,15 @@ def run(cell, *, seed: int, window, rec, dev) -> Outcome:
           file=sys.stderr)
     limits = config["limits"]
     checks.update({k: check(v, limits[k]) for k, v in gaps.items()})
+    if mesh is not None:
+        t_one = time.monotonic()
+        differing, steps = one_device_check(
+            run_cells, sweep_cells(1), results[0], cell.chips, config["step"])
+        checks["cells_differing_from_one_device"] = check(differing, 0)
+        print(f"bench: the first window sweep again on one device, a "
+              f"chip's share at a time, {time.monotonic() - t_one:.1f} s; "
+              f"engine steps {results[0].n_steps} sharded, {steps} alone",
+              file=sys.stderr)
     attempted = n * len(results)
     return Outcome(
         e2e={"engine_cells_per_s": done / window.elapsed},

@@ -2,9 +2,11 @@
 
 Set-up builds one ``FaultTolerantTrainer`` with its ``AsyncCheckpointer``
 from the configuration file, with weights and data drawn from ``--seed``,
-and calls ``run`` once.  The benchmark stands between the trainer and its
-attributes (``train_step``, ``ckpt.save``, ``ckpt.wait``,
-``data.batch_at``) to time them, without a change to the program:
+and calls ``run`` once.  The model is the configuration's ``model_type``:
+``bench/models/<model_type>.py`` gives the program's ModelConfig.  The
+benchmark stands between the trainer and its attributes (``train_step``,
+``ckpt.save``, ``ckpt.wait``, ``data.batch_at``) to time them, without a
+change to the program:
 
 * the first ``warm_steps`` calls of the step are set-up.  They compile (or
   load) the step, and their losses, the first gradient as the optimizer
@@ -19,7 +21,8 @@ attributes (``train_step``, ``ckpt.save``, ``ckpt.wait``,
   fingerprints.
 
 Then the program's state is freed and the float32 reference
-(``bench/reference/olmo.py``) runs the same steps from the same seed.
+(``bench/reference/<model_type>.py``) runs the same steps from the same
+seed.
 """
 from __future__ import annotations
 
@@ -32,7 +35,7 @@ from typing import Any, Dict, List, Optional
 from bench import registry, seeds
 from bench.compare import check, max_rel_gap, worst_leaf_gap
 from bench.harness import Outcome
-from bench.reference import ckpt_image, olmo
+from bench.reference import ckpt_image
 
 SEED_TAG = 0x6F6C6D6F   # "olmo"
 CKPT_DIR = registry.BENCH_DIR / ".ckpt"
@@ -40,29 +43,6 @@ CKPT_DIR = registry.BENCH_DIR / ".ckpt"
 
 class WindowClosed(Exception):
     """Raised from the step to end ``FaultTolerantTrainer.run``."""
-
-
-def model_config(cfg: Dict[str, Any]):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import AttentionConfig, ModelConfig, RopeConfig
-
-    run = cfg["run"]
-    heads = int(cfg["num_attention_heads"])
-    if cfg["hidden_act"] != "silu" or cfg["layer_norm"] != "non-parametric":
-        raise ValueError("this module runs OLMo blocks: SwiGLU, "
-                         "non-parametric LayerNorm")
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=int(cfg["num_hidden_layers"]), d_model=int(cfg["hidden_size"]),
-        d_ff=int(cfg["intermediate_size"]), vocab=int(cfg["vocab_size"]),
-        attention=AttentionConfig(
-            n_heads=heads, n_kv_heads=int(cfg["num_key_value_heads"]),
-            head_dim=int(cfg["hidden_size"]) // heads,
-            rope=RopeConfig(theta=float(cfg["rope_theta"]))),
-        norm="nonparametric", norm_eps=float(cfg["layer_norm_eps"]),
-        act="silu_gated", tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        param_dtype=run["param_dtype"], compute_dtype=run["compute_dtype"],
-        remat=run["remat"])
 
 
 def leaf_name(path) -> str:
@@ -191,7 +171,7 @@ def run(cell, *, seed: int, window, rec, dev) -> Outcome:
     ckpt = AsyncCheckpointer(str(root), n_shards=int(run_cfg["checkpoint_shards"]))
     opt = AdamWConfig(**run_cfg["optimizer"])
     trainer = FaultTolerantTrainer(
-        model_config(cfg),
+        registry.model(cfg["model_type"]).model_config(cfg),
         DataConfig(vocab=int(cfg["vocab_size"]), seq_len=int(run_cfg["seq_len"]),
                    global_batch=int(run_cfg["global_batch"]), seed=s32,
                    zipf_a=float(data["zipf_a"])),
@@ -224,7 +204,8 @@ def run(cell, *, seed: int, window, rec, dev) -> Outcome:
     # -- checks, after the program's state is freed -------------------- #
     limits = cfg["limits"]
     t_ref = time.monotonic()
-    ref = olmo.train(cfg, data, s32, len(probe.warm_losses))
+    ref = registry.reference(cfg["model_type"]).train(
+        cfg, data, s32, len(probe.warm_losses))
     t_ref = time.monotonic() - t_ref
     readings = {
         "loss1_rel_gap": max_rel_gap(probe.warm_losses[:1], ref.losses[:1]),

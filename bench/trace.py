@@ -4,14 +4,17 @@ A TPU plane is named ``/device:TPU:<n>``.  Its ``XLA Modules`` line holds
 one event per program run on the device; those events do not nest, so the
 union of their intervals is the time the device was busy.  Its ``XLA Ops``
 line holds the operations inside them (nested: a loop and its body both
-appear), which are used only to rank operations by time.  The host plane
-``/host:CPU`` holds the benchmark's own spans, named ``bench.<name>``
-(``spans.Recorder``), on the same clock.
+appear), which rank operations by time and give the device time of
+chosen ones, such as the collectives.  The host plane ``/host:CPU`` holds,
+on the same clock and on one line per thread, the benchmark's own spans,
+named ``bench.<name>`` (``spans.Recorder``), and the program's, named
+``repro.<name>`` (``repro.tracing``).
 
 ``reduce_trace`` gives, for the traced window (the ``bench.window`` span):
 busy seconds per device and their mean, the device time of each program,
 the operations that took most time, and the idle time on the device
-grouped by the innermost benchmark span that was open on the host then.
+grouped by the innermost benchmark or program span that was open on the
+host then.
 """
 from __future__ import annotations
 
@@ -24,6 +27,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from bench.spans import SPAN_PREFIX
 
 WINDOW_SPAN = SPAN_PREFIX + "window"
+PROGRAM_SPAN_PREFIX = "repro."
+HOST_SPAN_PREFIXES = (SPAN_PREFIX, PROGRAM_SPAN_PREFIX)
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 NO_SPAN = "no benchmark span"
 _MODULE_ID = re.compile(r"\(\d+\)$")
@@ -39,6 +44,7 @@ class TraceSummary:
     module_runs: Dict[str, int]
     top_ops: List[Tuple[str, float]]     # by total device seconds
     idle_by_span: List[Tuple[str, float]]
+    op_s: Dict[str, float]               # operation -> device seconds, all
 
     @property
     def n_devices(self) -> int:
@@ -63,6 +69,14 @@ class TraceSummary:
         secs = sum(s for n, s in self.module_s.items() if rx.search(n))
         runs = sum(r for n, r in self.module_runs.items() if rx.search(n))
         return secs, runs
+
+    def op_seconds(self, pattern: str) -> float:
+        """Device seconds of operations whose name matches, averaged over
+        the devices in the trace.  Operations are read over the whole
+        trace, which the profiler takes around the traced window only."""
+        rx = re.compile(pattern)
+        secs = sum(s for n, s in self.op_s.items() if rx.search(n))
+        return secs / max(self.n_devices, 1)
 
 
 def union(intervals: Sequence[Interval]) -> List[Interval]:
@@ -136,8 +150,8 @@ def reduce_trace(path: str, top: int = 10) -> TraceSummary:
 
 
 def load_events(path: str):
-    """Benchmark host spans, each device's program runs, and the device
-    seconds of each operation, from an ``.xplane.pb`` file."""
+    """Benchmark and program host spans, each device's program runs, and
+    the device seconds of each operation, from an ``.xplane.pb`` file."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -149,7 +163,7 @@ def load_events(path: str):
         if plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
+                    if e.name.startswith(HOST_SPAN_PREFIXES):
                         host_spans.append((e.name, e.start_ns * 1e-9,
                                            (e.start_ns + e.duration_ns) * 1e-9))
         elif m:
@@ -203,4 +217,4 @@ def summarize(host_spans: Sequence[Tuple[str, float, float]],
         window_s=hi - lo, busy_s_per_device=busy_per_dev,
         module_s=module_s, module_runs=module_runs,
         top_ops=[(k, v / n_dev) for k, v in top_ops],
-        idle_by_span=idle_mean[:top])
+        idle_by_span=idle_mean[:top], op_s=dict(op_time))
